@@ -155,7 +155,7 @@ def run_obs_smoke(out="BENCH_obs_smoke.json", gate: float = 0.03):
     on_us = time_fn(fn, qs)
     obs.configure(metrics=True, trace=False)
 
-    h = obs.REGISTRY.value("engine_op_seconds", path="search")
+    h = obs.REGISTRY.value("dispatch_staging_seconds", path="search")
     assert h is not None and h.count > 0, \
         "instrumented run recorded no search histogram samples"
     assert obs.TRACER.events(), "instrumented run recorded no spans"
@@ -190,8 +190,8 @@ def run_specialize_smoke(out="BENCH_tune.json", gate_tol: float = 0.10,
     dominate on CPU, so this is a trend gate like the device>=host one)
     and strictly faster on at least one. Both legs are measured through
     ``obs`` registries — the exact mean sidecar of
-    ``engine_op_seconds{path="lookup"}`` — never a parallel timer, and
-    the reps alternate postures so clock drift cancels.
+    ``dispatch_staging_seconds{path="lookup"}`` — never a parallel timer,
+    and the reps alternate postures so clock drift cancels.
 
     Then the micro autotune sweep runs, persists its platform profile,
     and ``tune.verify_profile`` reloads it via ``IndexConfig.from_tuned``
@@ -233,7 +233,7 @@ def run_specialize_smoke(out="BENCH_tune.json", gate_tol: float = 0.10,
                     with use_registry(r):
                         st.lookup(q).rank.block_until_ready()
                     ts.append(r.merged_histogram(
-                        "engine_op_seconds", path="lookup").mean)
+                        "dispatch_staging_seconds", path="lookup").mean)
             spec.close()
             args.close()
             med_s = float(np.median(t_spec))

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from . import sorted_array, css_tree, kary, fast_tree, nitrogen
+from ..obs import span
 
 KINDS = ("binary", "css", "kary", "fast", "nitrogen", "tiered")
 
@@ -357,14 +358,22 @@ class Index:
                                 vsum=vsum, vmin=vmin, vmax=vmax)
 
     def lookup(self, queries) -> LookupResult:
-        q = jnp.asarray(queries)
-        rank = self.search(q)
-        safe = jnp.minimum(rank, self.n - 1)
-        found = (rank < self.n) & (jnp.take(self.keys_sorted, safe, axis=0) == q)
-        vals = None
-        if self.values_sorted is not None:
-            vals = jnp.take(self.values_sorted, safe, axis=0)
-        return LookupResult(rank=rank, found=found, values=vals)
+        """Ranks, found flags and values. Three spans under
+        ``index.lookup``: ``lookup.put`` (the batch to the device), the
+        search (``tiered.search`` for the tiered kind), ``lookup.gather``
+        (found and values from the sorted columns)."""
+        with span("index.lookup", kind=self.config.kind):
+            with span("lookup.put"):
+                q = jnp.asarray(queries)
+            rank = self.search(q)
+            with span("lookup.gather"):
+                safe = jnp.minimum(rank, self.n - 1)
+                found = (rank < self.n) & \
+                    (jnp.take(self.keys_sorted, safe, axis=0) == q)
+                vals = None
+                if self.values_sorted is not None:
+                    vals = jnp.take(self.values_sorted, safe, axis=0)
+            return LookupResult(rank=rank, found=found, values=vals)
 
     def delete(self, keys):
         """Frozen indexes have no write path — deletes need the mutable

@@ -51,6 +51,7 @@ wrapped ``search_fn`` itself does (transfer-guard tested).
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -115,10 +116,17 @@ class QueueFuture:
     bounds; the per-caller slice is taken lazily on first ``result()`` —
     slicing a device array stages a device op, and doing it at consumption
     time keeps the flush itself free of anything but the fused dispatch
-    (the transfer-guard contract)."""
+    (the transfer-guard contract).
 
-    def __init__(self, queue: "MicroBatchQueue"):
+    ``req`` is the queue's id for this submit and ``flush`` the sequence
+    number of the flush that served it (None until then): the ``queue.*``
+    spans carry both, so one request's submit, flush and result link up in
+    a trace export across the threads that ran them."""
+
+    def __init__(self, queue: "MicroBatchQueue", req: int):
         self._queue = queue
+        self.req = req
+        self.flush: Optional[int] = None
         self._event = threading.Event()
         self._raw: Any = None
         self._bounds: Optional[tuple] = None
@@ -135,9 +143,10 @@ class QueueFuture:
         triggers (deadline timer, other callers) to do the flushing."""
         return self._event.wait(timeout)
 
-    def _resolve(self, shared_result: Any, lo: int, hi: int):
+    def _resolve(self, shared_result: Any, lo: int, hi: int, flush: int):
         self._raw = shared_result
         self._bounds = (lo, hi)
+        self.flush = flush
         self._event.set()
 
     def _reject(self, err: BaseException):
@@ -145,10 +154,37 @@ class QueueFuture:
         self._event.set()
 
     def result(self, timeout: Optional[float] = None) -> Any:
-        with span("queue.result", path=self._queue.path):
-            return self._result(timeout)
+        """Two stages, each a span and a histogram: ``queue.wait`` until
+        the batch is resolved (demand flushes included), then, on the
+        first call only, ``queue.slice`` staging this caller's rows."""
+        queue = self._queue
+        path = queue.path
+        with span("queue.result", path=path, req=self.req) as sp:
+            with span("queue.wait", path=path, req=self.req):
+                t0 = time.perf_counter()
+                try:
+                    self._wait(timeout)
+                finally:
+                    queue._metric("histogram",
+                                  "queue_result_wait_seconds").observe(
+                        time.perf_counter() - t0)
+            sp.set(flush=self.flush)
+            if self._error is not None:
+                raise self._error
+            if not self._sliced:
+                with span("queue.slice", path=path, req=self.req):
+                    t0 = time.perf_counter()
+                    lo, hi = self._bounds
+                    self._value = jax.tree.map(lambda leaf: leaf[lo:hi],
+                                               self._raw)
+                    queue._metric("histogram",
+                                  "queue_slice_seconds").observe(
+                        time.perf_counter() - t0)
+                self._raw = None              # drop the shared batch ref
+                self._sliced = True
+            return self._value
 
-    def _result(self, timeout: Optional[float]) -> Any:
+    def _wait(self, timeout: Optional[float]):
         while not self._event.is_set():
             # demand-flush until OUR submit is admitted: a capped flush can
             # serve other tenants first, so one flush is not always enough
@@ -157,14 +193,31 @@ class QueueFuture:
                 break                         # nothing pending anywhere
         if not self._event.wait(timeout):
             raise TimeoutError("micro-batch result not ready")
-        if self._error is not None:
-            raise self._error
-        if not self._sliced:
-            lo, hi = self._bounds
-            self._value = jax.tree.map(lambda leaf: leaf[lo:hi], self._raw)
-            self._raw = None                  # drop the shared batch ref
-            self._sliced = True
-        return self._value
+
+
+class _TimedLock:
+    """``with queue._locked(op):`` — a class, not a generator, as it is
+    taken on every submit and flush."""
+    __slots__ = ("_queue", "_op")
+
+    def __init__(self, queue: "MicroBatchQueue", op: str):
+        self._queue = queue
+        self._op = op
+
+    def __enter__(self):
+        q = self._queue
+        t0 = time.perf_counter()
+        q._lock.acquire()
+        try:
+            q._metric("histogram", "queue_lock_wait_seconds",
+                      op=self._op).observe(time.perf_counter() - t0)
+        except BaseException:
+            q._lock.release()
+            raise
+
+    def __exit__(self, *exc):
+        self._queue._lock.release()
+        return False
 
 
 def _leading_dim(queries) -> int:
@@ -246,6 +299,10 @@ class MicroBatchQueue:
         self._now = now_fn
         self._use_timer = bool(timer)
         self._lock = threading.RLock()
+        self._req_ids = itertools.count()
+        self._flush_seq = 0
+        self._metrics_reg = None      # registry self._metrics resolve in
+        self._metrics: Dict[tuple, Any] = {}
         # per-tenant FIFO lanes of (queries, q_n, future, t_enqueued)
         self._lanes: Dict[Any, deque] = {}
         self._pending_queries = 0
@@ -301,10 +358,9 @@ class MicroBatchQueue:
                 queries, (tuple, list, dict)):
             queries = np.asarray(queries)
         q_n = _leading_dim(queries)
-        fut = QueueFuture(self)
-        reg = get_registry()
-        with span("queue.submit", path=self.path, tenant=tenant, n=q_n), \
-                self._lock:
+        fut = QueueFuture(self, next(self._req_ids))
+        with span("queue.submit", path=self.path, tenant=tenant, n=q_n,
+                  req=fut.req), self._locked("submit"):
             if self._closed:
                 raise RuntimeError("submit on a closed MicroBatchQueue")
             ts = self.stats.tenant(tenant)
@@ -315,8 +371,8 @@ class MicroBatchQueue:
                     self._lane_queries(lane) + q_n > self.max_backlog:
                 ts.drops += 1
                 self.stats.drops += 1
-                reg.counter("queue_drops", path=self.path,
-                            tenant=str(tenant)).inc()
+                self._metric("counter", "queue_drops",
+                             tenant=str(tenant)).inc()
                 fut._reject(QueueOverflow(
                     f"tenant {tenant!r} backlog over {self.max_backlog} "
                     f"queries"))
@@ -336,10 +392,10 @@ class MicroBatchQueue:
             self.stats.queries += q_n
             ts.submits += 1
             ts.queries += q_n
-            reg.counter("queue_submits", path=self.path,
-                        tenant=str(tenant)).inc()
-            reg.counter("queue_queries", path=self.path,
-                        tenant=str(tenant)).inc(q_n)
+            self._metric("counter", "queue_submits",
+                         tenant=str(tenant)).inc()
+            self._metric("counter", "queue_queries",
+                         tenant=str(tenant)).inc(q_n)
             if self._pending_queries >= min(self.flush_at, self.capacity):
                 # admission packs at most `capacity` per flush; keep going
                 # until the backlog is back under the trigger
@@ -355,13 +411,34 @@ class MicroBatchQueue:
     def _lane_queries(lane) -> int:
         return sum(n for _, n, _, _ in lane)
 
+    def _metric(self, kind: str, name: str, **labels):
+        """This queue's series of a registry metric (``kind`` is counter,
+        gauge or histogram; label values are strings), resolved once per
+        active registry: a registry lookup sorts and formats the labels,
+        and a request touches a dozen series."""
+        reg = get_registry()
+        if self._metrics_reg is not reg:
+            self._metrics, self._metrics_reg = {}, reg
+        key = (name, *labels.values())
+        m = self._metrics.get(key)
+        if m is None:
+            m = self._metrics[key] = getattr(reg, kind)(
+                name, path=self.path, **labels)
+        return m
+
+    def _locked(self, op: str) -> "_TimedLock":
+        """The queue lock, observing how long taking it waited
+        (``queue_lock_wait_seconds{op}``): a flush stages its dispatch
+        under the lock, so submits and other flushes queue behind it."""
+        return _TimedLock(self, op)
+
     # -------------------------------------------------------------- flush
     def flush(self, reason: str = "manual") -> int:
         """Dispatch one admitted batch as ONE fused dispatch; returns the
         number of queries dispatched (0 when nothing was pending). Under
         admission caps a flush may leave work behind — it re-arms the
         deadline timer for the leftovers."""
-        with self._lock:
+        with self._locked(reason):
             return self._flush_locked(reason)
 
     def drain(self) -> int:
@@ -382,20 +459,20 @@ class MicroBatchQueue:
             self._timer = None
         if not any(self._lanes.values()):
             return 0
-        with span("queue.flush", path=self.path, reason=reason):
-            return self._flush_admitted(reason)
+        self._flush_seq += 1
+        seq = self._flush_seq
+        with span("queue.flush", path=self.path, reason=reason, flush=seq):
+            return self._flush_admitted(reason, seq)
 
-    def _flush_admitted(self, reason: str) -> int:
-        reg = get_registry()
+    def _flush_admitted(self, reason: str, seq: int) -> int:
         # resolve the previous flush's occupancy feedback now: its dispatch
         # has retired (or is about to, ahead of ours on the device stream),
         # so this never stalls an enqueueing caller the way draining in
         # submit() would
         self.drain_feedback()
-        with span("queue.admit", path=self.path):
-            admit = self.admission.plan(
-                {t: [n for _, n, _, _ in lane]
-                 for t, lane in self._lanes.items() if lane})
+        admit = self.admission.plan(
+            {t: [n for _, n, _, _ in lane]
+             for t, lane in self._lanes.items() if lane})
         now = self._now()
         batch = []                          # (queries, q_n, fut, tenant)
         for t in admit.service:
@@ -406,10 +483,10 @@ class MicroBatchQueue:
             wait = max(now - t_enq, 0.0)
             ts.wait_s += wait
             ts.wait_max_s = max(ts.wait_max_s, wait)
-            reg.counter("queue_admitted", path=self.path,
-                        tenant=str(t)).inc(q_n)
-            reg.histogram("queue_wait_seconds", path=self.path,
-                          tenant=str(t)).observe(wait)
+            self._metric("counter", "queue_admitted",
+                         tenant=str(t)).inc(q_n)
+            self._metric("histogram", "queue_wait_seconds",
+                         tenant=str(t)).observe(wait)
         if not batch:
             return 0
         total = admit.total
@@ -419,8 +496,8 @@ class MicroBatchQueue:
             if lane:
                 leftovers = True
                 self.stats.tenant(t).deferred += len(lane)
-                reg.counter("queue_deferred", path=self.path,
-                            tenant=str(t)).inc(len(lane))
+                self._metric("counter", "queue_deferred",
+                             tenant=str(t)).inc(len(lane))
         self._oldest_t = min(
             (lane[0][3] for lane in self._lanes.values() if lane),
             default=None)
@@ -431,8 +508,8 @@ class MicroBatchQueue:
         for t, n in admit.counts.items():
             if n or t in {b[3] for b in batch}:
                 self.stats.tenant(t).flushes += 1
-                reg.counter("queue_tenant_flushes", path=self.path,
-                            tenant=str(t)).inc()
+                self._metric("counter", "queue_tenant_flushes",
+                             tenant=str(t)).inc()
         if self.flush_log is not None:
             subs: Dict[Any, int] = {}
             for t in admit.service:
@@ -444,9 +521,9 @@ class MicroBatchQueue:
         if not hasattr(self.stats, counter):   # free-text reason: file under
             counter = "manual_flushes"         # manual instead of raising
         setattr(self.stats, counter, getattr(self.stats, counter) + 1)
-        reg.counter("queue_flushes", path=self.path, reason=reason).inc()
-        reg.histogram("queue_batch_size", path=self.path).observe(total)
-        reg.gauge("queue_flush_at", path=self.path).set(self.flush_at)
+        self._metric("counter", "queue_flushes", reason=reason).inc()
+        self._metric("histogram", "queue_batch_size").observe(total)
+        self._metric("gauge", "queue_flush_at").set(self.flush_at)
         try:
             parts = [q for q, n, _, _ in batch if n]
             pad = (_next_pow2(total) - total) if (self.pad_pow2 and total) \
@@ -455,12 +532,13 @@ class MicroBatchQueue:
             # dispatch-boundary timer: measures the host-side *staging*
             # cost of the one fused dispatch (search_fn returns without
             # waiting on the device), so observing it adds no sync
-            with span("queue.dispatch", path=self.path, n=total, pad=pad):
+            with span("queue.dispatch", path=self.path, n=total, pad=pad,
+                      flush=seq):
                 t0 = time.perf_counter()
                 result, occ_thunk = self._search_fn(q)
-                reg.histogram("engine_op_seconds", path=self.path).observe(
+                self._metric("histogram",
+                             "dispatch_staging_seconds").observe(
                     time.perf_counter() - t0)
-                reg.counter("engine_ops", path=self.path).inc()
             if occ_thunk is not None:
                 # the engine saw the padded batch; scale its occupancy back
                 # to real queries so pad lanes never flatter the steering
@@ -469,7 +547,7 @@ class MicroBatchQueue:
             lo = 0
             for _, n, fut, _ in batch:
                 hi = lo + n
-                fut._resolve(result, lo, hi)
+                fut._resolve(result, lo, hi, seq)
                 lo = hi
         except BaseException as e:            # noqa: BLE001 — futures must not hang
             for _, _, fut, _ in batch:
@@ -519,7 +597,7 @@ class MicroBatchQueue:
         timer.start()
 
     def _on_deadline(self, me: threading.Timer):
-        with self._lock:
+        with self._locked("deadline"):
             if self._closed or self._timer is not me:
                 return                        # closed, or cancelled and
             self._timer = None                # superseded: a newer timer
@@ -528,18 +606,27 @@ class MicroBatchQueue:
             window = self.effective_deadline()
             age = self._now() - (self._oldest_t or 0.0)
             if age + 1e-6 >= window:
-                self._flush_locked("deadline")
+                self._deadline_flush(age, window)
             else:                             # raced a fresh batch: re-arm
                 self._arm_timer(window - age)
+
+    def _deadline_flush(self, age: float, window: float) -> int:
+        """Flush on the deadline, observing how far past the effective
+        window the oldest submit got (``queue_deadline_late_seconds``)."""
+        self._metric("histogram", "queue_deadline_late_seconds").observe(
+            max(age - window, 0.0))
+        return self._flush_locked("deadline")
 
     def poll(self) -> int:
         """Timer-free deadline check (virtual-clock benchmarks / manual
         drivers): flush iff the oldest pending submit has aged past the
         effective window."""
-        with self._lock:
-            if any(self._lanes.values()) and \
-                    self._now() - self._oldest_t >= self.effective_deadline():
-                return self._flush_locked("deadline")
+        with self._locked("deadline"):
+            if any(self._lanes.values()):
+                window = self.effective_deadline()
+                age = self._now() - self._oldest_t
+                if age >= window:
+                    return self._deadline_flush(age, window)
         return 0
 
     # ----------------------------------------------------------- feedback
@@ -553,19 +640,17 @@ class MicroBatchQueue:
         flush's tenants by lane share for the per-tenant ledger."""
         with self._lock:
             pending, self._feedback = self._feedback, []
-        reg = get_registry()
         for thunk, real, dispatched, counts in pending:
             occ = float(thunk()) * (real / dispatched if dispatched else 0.0)
             self.stats.occ_sum += occ
             self.stats.occ_n += 1
-            reg.histogram("queue_flush_occupancy",
-                          path=self.path).observe(occ)
+            self._metric("histogram", "queue_flush_occupancy").observe(occ)
             for t, share in occupancy_shares(counts, occ).items():
                 ts = self.stats.tenant(t)
                 ts.occ_sum += share
                 ts.occ_n += 1
-                reg.histogram("queue_occupancy", path=self.path,
-                              tenant=str(t)).observe(share)
+                self._metric("histogram", "queue_occupancy",
+                             tenant=str(t)).observe(share)
             if not self.adapt:
                 continue
             if occ < self.occupancy_target:

@@ -476,9 +476,9 @@ class TieredScanner:
                     cnt, vs, mn, mx, r_lo, r_hi = self.agg_fn(mode)(
                         lo, hi, kp, vp, self.aux)
                 reg = get_registry()
-                reg.histogram("engine_op_seconds", path="scan").observe(
+                reg.histogram("dispatch_staging_seconds",
+                              path="scan").observe(
                     time.perf_counter() - t0)
-                reg.counter("engine_ops", path="scan").inc()
             return ScanResult(count=cnt, r_lo=r_lo, r_hi_excl=r_hi,
                               vsum=vs, vmin=mn, vmax=mx)
         # materialize composes with the requested aggregates in the SAME
@@ -523,9 +523,9 @@ class TieredScanner:
                 cnt, vs, mn, mx, r_lo, r_hi, ranks, vals, over = fn(
                     lo, hi, kp, vp_mat, self.aux, self.values_dev)
             reg = get_registry()
-            reg.histogram("engine_op_seconds", path="scan").observe(
+            reg.histogram("dispatch_staging_seconds",
+                          path="scan").observe(
                 time.perf_counter() - t0)
-            reg.counter("engine_ops", path="scan").inc()
         return ScanResult(count=cnt, r_lo=r_lo, r_hi_excl=r_hi,
                           vsum=vs, vmin=mn, vmax=mx,
                           ranks=ranks, values=vals, overflow=over)
@@ -611,9 +611,9 @@ class TieredScanner:
                 out = fn(*lo_args, self.index.pages, self.vpages,
                          self.aux, self.values_dev)
             reg = get_registry()
-            reg.histogram("engine_op_seconds", path=path).observe(
+            reg.histogram("dispatch_staging_seconds",
+                          path=path).observe(
                 time.perf_counter() - t0)
-            reg.counter("engine_ops", path=path).inc()
         return out
 
     def scan_groups(self, lo, hi, num_groups: int, *, aggs=None,

@@ -519,7 +519,6 @@ class MutableIndex:
                     reg = get_registry()
                     reg.histogram("engine_op_seconds", path="journal") \
                         .observe(time.perf_counter() - t0)
-                    reg.counter("engine_ops", path="journal").inc()
             for k, v in zip(keys, values):
                 if self.delta.full:
                     self._seal()
@@ -566,7 +565,6 @@ class MutableIndex:
                 self.maintain()
             self.delta, self.sealed = self.sealed, self.delta
             self.stats["seals"] += 1
-            get_registry().counter("engine_ops", path="seal").inc()
             self._rev += 1
             if self._mode == "inline":
                 self.maintain()
@@ -592,7 +590,6 @@ class MutableIndex:
                 reg = get_registry()
                 reg.histogram("engine_op_seconds", path="fold").observe(
                     time.perf_counter() - t0)
-                reg.counter("engine_ops", path="fold").inc()
             self.delta.promote_ss()
             return True
 
@@ -715,9 +712,9 @@ class MutableIndex:
                 rank, found, vals, _ = self._fused(q, *tiers)
                 self._last_plan = None
             reg = get_registry()
-            reg.histogram("engine_op_seconds", path="lookup").observe(
+            reg.histogram("dispatch_staging_seconds",
+                          path="lookup").observe(
                 time.perf_counter() - t0)
-            reg.counter("engine_ops", path="lookup").inc()
         return LookupResult(rank=rank, found=found, values=vals)
 
     def pop_plan_feedback(self):
@@ -840,9 +837,9 @@ class MutableIndex:
                 t0 = time.perf_counter()
                 count, vsum, vmin, vmax, r_lo, r_hi = fn(*args)
                 reg = get_registry()
-                reg.histogram("engine_op_seconds", path="scan").observe(
+                reg.histogram("dispatch_staging_seconds",
+                              path="scan").observe(
                     time.perf_counter() - t0)
-                reg.counter("engine_ops", path="scan").inc()
             return _scan.ScanResult(count=count, r_lo=r_lo, r_hi_excl=r_hi,
                                     vsum=vsum, vmin=vmin, vmax=vmax)
         K = int(materialize)
@@ -855,9 +852,9 @@ class MutableIndex:
             count, vsum, vmin, vmax, r_lo, r_hi, ranks, vals, over = \
                 fn(*args)
             reg = get_registry()
-            reg.histogram("engine_op_seconds", path="scan").observe(
+            reg.histogram("dispatch_staging_seconds",
+                          path="scan").observe(
                 time.perf_counter() - t0)
-            reg.counter("engine_ops", path="scan").inc()
         return _scan.ScanResult(count=count, r_lo=r_lo, r_hi_excl=r_hi,
                                 vsum=vsum, vmin=vmin, vmax=vmax,
                                 ranks=ranks, values=vals, overflow=over)
@@ -930,10 +927,9 @@ class MutableIndex:
             t0 = time.perf_counter()
             out = fn(*args)
             reg = get_registry()
-            reg.histogram("engine_op_seconds",
+            reg.histogram("dispatch_staging_seconds",
                           path="scan_groups").observe(
                 time.perf_counter() - t0)
-            reg.counter("engine_ops", path="scan_groups").inc()
         edges, r_edge, count, vsum, vmin, vmax = out[:6]
         if K is None:
             return _gb.GroupScanResult(count=count, edges=edges,
@@ -984,10 +980,9 @@ class MutableIndex:
             t0 = time.perf_counter()
             count, vsum, vmin, vmax, r_lo, r_hi = fn(*args)
             reg = get_registry()
-            reg.histogram("engine_op_seconds",
+            reg.histogram("dispatch_staging_seconds",
                           path="scan_multi").observe(
                 time.perf_counter() - t0)
-            reg.counter("engine_ops", path="scan_multi").inc()
         return _scan.ScanResult(count=count, r_lo=r_lo, r_hi_excl=r_hi,
                                 vsum=vsum, vmin=vmin, vmax=vmax)
 
@@ -1215,7 +1210,6 @@ class MutableIndex:
             reg.histogram("engine_op_seconds",
                           path="snapshot_save").observe(
                               time.perf_counter() - t0)
-            reg.counter("engine_ops", path="snapshot_save").inc()
             return path
 
     def _rotate_journal(self, ckpt_dir: str, step: int):
@@ -1300,7 +1294,6 @@ class MutableIndex:
         reg = get_registry()
         reg.histogram("engine_op_seconds", path="snapshot_restore") \
             .observe(time.perf_counter() - t_start)
-        reg.counter("engine_ops", path="snapshot_restore").inc()
         return self
 
     def _replay(self, ckpt_dir: str, from_step: int):

@@ -336,9 +336,9 @@ def search(index: TieredIndex, queries, *, plan: str | None = None
         else:
             out = index.search_fused(q, index.pages)
         reg = get_registry()
-        reg.histogram("engine_op_seconds", path="search").observe(
+        reg.histogram("dispatch_staging_seconds",
+                      path="search").observe(
             time.perf_counter() - t0)
-        reg.counter("engine_ops", path="search").inc()
     return out
 
 

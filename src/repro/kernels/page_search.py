@@ -93,11 +93,13 @@ def _kernel(page_ids_ref, q_ref, pages_ref, o_ref, *, stride: int):
 
 
 def bucketed_call(kern, lanes_in, page_ids, page_arrays, out_dtypes,
-                  interpret):
+                  interpret, name=None):
     """One scalar-prefetched pallas_call over G page buckets: ``lanes_in``
     are [G, TQ] per-lane operands, ``page_arrays`` the [P, R, 128] page
     storages streamed at ``page_ids[g]``; returns one [G, TQ] array per
-    entry of ``out_dtypes``."""
+    entry of ``out_dtypes``. ``name`` names the kernel's op in a device
+    trace whatever ``lax.switch`` rung runs it (unnamed, a rung's op shows
+    as ``branch_<k>_fun``)."""
     G, TQ = lanes_in[0].shape
     lane_spec = pl.BlockSpec((None, 1, TQ), lambda g, pids: (g, 0, 0))
     _, rows, lanes = page_arrays[0].shape
@@ -115,6 +117,7 @@ def bucketed_call(kern, lanes_in, page_ids, page_arrays, out_dtypes,
         out_shape=tuple(jax.ShapeDtypeStruct((G, 1, TQ), d)
                         for d in out_dtypes),
         interpret=_ops.use_interpret(interpret),
+        name=name,
     )(page_ids, *(x.reshape(G, 1, TQ) for x in lanes_in), *page_arrays)
     return tuple(o.reshape(G, TQ) for o in outs)
 
@@ -136,7 +139,7 @@ def page_search_bucketed(queries_bucketed: jnp.ndarray, page_ids: jnp.ndarray,
     """
     kern = functools.partial(_kernel, stride=stride)
     (out,) = bucketed_call(kern, (queries_bucketed,), page_ids, (pages,),
-                           (jnp.int32,), interpret)
+                           (jnp.int32,), interpret, name="page_kernel")
     return out
 
 

@@ -235,12 +235,12 @@ def _metrics_selftest(port: int):
     parsed = obs.parse_prometheus(body)
     names = {n for n, _ in parsed}
     required = ["repro_queue_submits_total", "repro_queue_flushes_total",
-                "repro_engine_op_seconds_bucket",
-                "repro_engine_op_seconds_count"]
+                "repro_dispatch_staging_seconds_bucket",
+                "repro_dispatch_staging_seconds_count"]
     missing = [n for n in required if n not in names]
     assert not missing, f"metrics selftest: missing series {missing}"
     paths = {lab for n, lab in parsed
-             if n == "repro_engine_op_seconds_count"}
+             if n == "repro_dispatch_staging_seconds_count"}
     assert any('path="probe"' in p for p in paths), paths
     print(f"metrics selftest: {len(parsed)} samples, "
           f"{len(names)} series ok")

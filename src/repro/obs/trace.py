@@ -42,6 +42,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -75,6 +78,11 @@ class _Span:
                 pass
         self._tracer._record(self.name, self._t0, dur, self.args)
         return False
+
+    def set(self, **args):
+        """Add labels known only inside the span (the flush that served a
+        request); they land in the event's ``args``, never in its name."""
+        self.args.update(args)
 
 
 class Tracer:
@@ -119,20 +127,6 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, args)
-
-    def instant(self, name: str, **args):
-        """Record a zero-duration instant event (scope: thread)."""
-        if not self.enabled:
-            return
-        now = time.perf_counter()
-        ev = {
-            "name": name, "ph": "i", "s": "t",
-            "ts": (now - self._epoch) * 1e6,
-            "pid": os.getpid(), "tid": threading.get_ident(),
-        }
-        if args:
-            ev["args"] = {k: _jsonable(v) for k, v in args.items()}
-        self._push(ev)
 
     def _record(self, name: str, t0: float, dur: float,
                 args: Dict[str, Any]):
@@ -196,6 +190,3 @@ def span(name: str, **args):
         return _NULL_SPAN
     return _Span(TRACER, name, args)
 
-
-def instant(name: str, **args):
-    TRACER.instant(name, **args)

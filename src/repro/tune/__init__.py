@@ -3,9 +3,10 @@
 Sweeps the index/queue knobs the prior PRs exposed — ``tile`` ×
 ``leaf_width`` × ``HISTOGRAM_MAX_PAGES`` × queue ``flush_at`` /
 ``queue_deadline_s`` — per jax backend, reading its objective from the
-metrics registry between trials (p50/p99 of ``engine_op_seconds``; there
-is NO parallel timing harness), and persists the winning knobs plus the
-registry snapshot as a platform profile under ``src/repro/configs/``.
+metrics registry between trials (p50/p99 of
+``dispatch_staging_seconds``; there is NO parallel timing harness), and
+persists the winning knobs plus the registry snapshot as a platform
+profile under ``src/repro/configs/``.
 ``IndexConfig.from_tuned(platform)`` loads it back.
 """
 from .profile import (  # noqa: F401

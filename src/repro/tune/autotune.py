@@ -3,8 +3,9 @@
 One **trial** = build a mutable tiered store at a sweep point, run the
 three serving legs (point lookups, range scans, micro-batch flushes)
 under a FRESH ``obs.Registry``, then read the objective out of that
-registry: p50/p99 bucket bounds of ``engine_op_seconds{path=...}`` plus
-the exact mean sidecar. There is no parallel timing harness — the tuner
+registry: p50/p99 bucket bounds of ``dispatch_staging_seconds{path=...}``
+(the host staging of each async dispatch, not its device time) plus the
+exact mean sidecar. There is no parallel timing harness — the tuner
 measures exactly what serving measures, through the same histograms.
 
 The sweep is staged to stay O(sum) instead of O(product): stage A sweeps
@@ -146,7 +147,7 @@ def _objective(reg: Registry) -> Tuple[Dict[str, Any],
     bucket_score = 0.0
     mean_score = 0.0
     for path, w in PATH_WEIGHTS:
-        h = reg.merged_histogram("engine_op_seconds", path=path)
+        h = reg.merged_histogram("dispatch_staging_seconds", path=path)
         obj[path] = {"p50": h.quantile(0.5), "p99": h.quantile(0.99),
                      "mean": h.mean, "count": h.count}
         bucket_score += w * (obj[path]["p50"] + 0.2 * obj[path]["p99"])
@@ -221,7 +222,7 @@ def verify_profile(prof: TunedProfile, *,
         for _ in range(reps):
             store.lookup(q).rank.block_until_ready()
     store.close()
-    fresh = reg.merged_histogram("engine_op_seconds",
+    fresh = reg.merged_histogram("dispatch_staging_seconds",
                                  path="lookup").quantile(0.5)
     recorded = float(prof.objective["lookup"]["p50"])
     lo_b, hi_b = recorded / _SQRT2, recorded * _SQRT2

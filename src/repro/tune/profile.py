@@ -10,7 +10,8 @@ static architecture configs — the platform-config idiom). It records:
 * ``objective`` — the registry-derived score of that point: per path
   (``lookup`` / ``scan`` / ``flush``) the p50/p99 bucket bounds, the
   exact mean, and the observation count, straight from
-  ``obs.Registry.merged_histogram("engine_op_seconds", path=...)``.
+  ``obs.Registry.merged_histogram("dispatch_staging_seconds", path=...)``,
+  the host staging time of each path's dispatches.
 * ``trials`` — every swept point with its score (the sweep's audit
   trail).
 * ``registry`` — the winning trial's full registry snapshot.

@@ -155,7 +155,7 @@ def test_prometheus_text_round_trips_through_parser():
     reg = Registry()
     reg.counter("queue_submits", path="probe", tenant='we"ird\\t').inc(7)
     reg.gauge("queue_flush_at", path="probe").set(64)
-    h = reg.histogram("engine_op_seconds", path="search")
+    h = reg.histogram("dispatch_staging_seconds", path="search")
     h.observe(0.001)
     h.observe(0.002)
     text = reg.prometheus_text()
@@ -165,19 +165,19 @@ def test_prometheus_text_round_trips_through_parser():
     # _bucket/_sum/_count with a +Inf rail
     assert "repro_queue_submits_total" in names
     assert "repro_queue_flush_at" in names
-    assert "repro_engine_op_seconds_bucket" in names
+    assert "repro_dispatch_staging_seconds_bucket" in names
     by_name = {}
     for (n, lab), v in parsed.items():
         by_name.setdefault(n, {})[lab] = v
     assert sum(by_name["repro_queue_submits_total"].values()) == 7
-    assert any('le="+Inf"' in lab and v == 2
-               for lab, v in by_name["repro_engine_op_seconds_bucket"].items())
-    assert sum(by_name["repro_engine_op_seconds_count"].values()) == 2
+    buckets = by_name["repro_dispatch_staging_seconds_bucket"]
+    assert any('le="+Inf"' in lab and v == 2 for lab, v in buckets.items())
+    assert sum(by_name["repro_dispatch_staging_seconds_count"].values()) == 2
     # cumulative le buckets are monotone non-decreasing
     rails = sorted(
         ((float("inf") if 'le="+Inf"' in lab else
           float(lab.split('le="')[1].split('"')[0])), v)
-        for lab, v in by_name["repro_engine_op_seconds_bucket"].items())
+        for lab, v in buckets.items())
     assert all(rails[i][1] <= rails[i + 1][1] for i in range(len(rails) - 1))
 
 
@@ -241,6 +241,24 @@ def test_span_nesting_in_export():
         assert e["ts"] >= outer["ts"]
         assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-6
     assert inner["ts"] + inner["dur"] <= evs["inner2"]["ts"] + 1e-6
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_set_adds_args(enabled):
+    """Labels known only inside a span (the flush that served a request)
+    join its ``args``; the event keeps its bare name. Disabled, ``set``
+    is a no-op on the shared null span."""
+    tr = Tracer()
+    if enabled:
+        tr.enable()
+    with tr.span("queue.result", req=7) as sp:
+        sp.set(flush=3)
+    if not enabled:
+        assert tr.events() == []
+        return
+    (ev,) = tr.events()
+    assert ev["name"] == "queue.result"
+    assert ev["args"] == {"req": 7, "flush": 3}
 
 
 def test_trace_export_writes_loadable_json(tmp_path):
@@ -309,20 +327,29 @@ def test_instrumented_flush_is_single_dispatch_no_transfers():
             with jax.transfer_guard("disallow"):
                 futs = [q.submit(r) for r in reqs]
                 q.flush()
+            # the result stages (wait, slice) read host clocks only: no
+            # device value comes back. (Slicing uploads its start index as
+            # a scalar, host to device, with or without instrumentation.)
+            with jax.transfer_guard_device_to_host("disallow"), \
+                    jax.transfer_guard_device_to_device("disallow"):
+                outs = [f.result() for f in futs]
         finally:
             trace_mod.TRACER = old
         assert q.stats.flushes == 1
-        for i, f in enumerate(futs):
-            np.testing.assert_array_equal(np.asarray(f.result().values),
+        for i, out in enumerate(outs):
+            np.testing.assert_array_equal(np.asarray(out.values),
                                           vals[i * 8:(i + 1) * 8])
         # the boundary timer recorded exactly the one dispatch
-        assert reg.total("engine_ops", path="probe") == 1
-        h = reg.merged_histogram("engine_op_seconds", path="probe")
+        h = reg.merged_histogram("dispatch_staging_seconds", path="probe")
         assert h.count == 1
         assert reg.total("queue_submits", path="probe") == 4
         assert reg.total("queue_flushes", path="probe") == 1
+        for name in ("queue_result_wait_seconds", "queue_slice_seconds"):
+            assert reg.merged_histogram(name, path="probe").count == 4
     names = [e["name"] for e in tr.events()]
-    assert "queue.dispatch" in names and "queue.flush" in names
+    for name in ("queue.dispatch", "queue.flush", "queue.result",
+                 "queue.wait", "queue.slice"):
+        assert name in names, name
 
 
 def test_queue_registry_series_and_tenant_summary():

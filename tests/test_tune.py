@@ -93,7 +93,7 @@ def test_run_trial_objective_comes_from_registry():
     assert t["score"][0] > 0.0
     # the trial ran under its own registry: the process registry did not
     # absorb the trial's lookups
-    assert "engine_op_seconds" in t["registry"]
+    assert "dispatch_staging_seconds" in t["registry"]
 
 
 def test_autotune_smoke_persists_and_verifies(tmp_path):
