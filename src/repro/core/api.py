@@ -9,6 +9,8 @@ the interface the paper-figure benchmarks drive.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import time
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -17,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from . import sorted_array, css_tree, kary, fast_tree, nitrogen
-from ..obs import span
+from ..obs import get_registry, span
 
 KINDS = ("binary", "css", "kary", "fast", "nitrogen", "tiered")
 
@@ -357,22 +359,71 @@ class Index:
         return _scan.ScanResult(count=count, r_lo=hlo, r_hi_excl=hhi,
                                 vsum=vsum, vmin=vmin, vmax=vmax)
 
+    @functools.cached_property
+    def _fused(self):
+        """The frozen tiered lookup as one program: ``(raw, data, owned,
+        shared)``, where ``raw(q, *data) -> (rank, found, values)`` is the
+        traceable composition of the device-plan pipeline with the clamp
+        and the found/value gathers, ``owned`` its jit that donates the
+        query buffer (int32 keys, as ``search_fused``) and ``shared`` its
+        jit that never does. The leaf pages and sorted columns ride in
+        ``data`` as jit arguments, so the table never enters an executable
+        (a specialized index bakes only its pages, as ``search_spec``
+        does). None for the other kinds and for ``plan="host"``, which
+        keep the eager lookup."""
+        impl = self.impl
+        if self.config.kind != "tiered" or impl.plan != "device":
+            return None
+        spec = impl.search_spec_raw
+        if spec is not None:
+            fused_lookup = _lookup_program(lambda q, _: spec(q))
+            data = (None, self.keys_sorted, self.values_sorted)
+        else:
+            fused_lookup = _lookup_program(impl.search_raw)
+            data = (impl.pages, self.keys_sorted, self.values_sorted)
+        shared = jax.jit(fused_lookup)
+        owned = jax.jit(fused_lookup, donate_argnums=(0,)) if impl.donate \
+            else shared
+        return fused_lookup, data, owned, shared
+
     def lookup(self, queries) -> LookupResult:
-        """Ranks, found flags and values. Three spans under
-        ``index.lookup``: ``lookup.put`` (the batch to the device), the
-        search (``tiered.search`` for the tiered kind), ``lookup.gather``
-        (found and values from the sorted columns)."""
-        with span("index.lookup", kind=self.config.kind):
-            with span("lookup.put"):
-                q = jnp.asarray(queries)
-            rank = self.search(q)
-            with span("lookup.gather"):
-                safe = jnp.minimum(rank, self.n - 1)
-                found = (rank < self.n) & \
-                    (jnp.take(self.keys_sorted, safe, axis=0) == q)
-                vals = None
-                if self.values_sorted is not None:
-                    vals = jnp.take(self.values_sorted, safe, axis=0)
+        """Ranks, found flags and values, under span ``index.lookup``.
+        The device-plan tiered kind answers in ONE jitted program (span
+        ``lookup.fused``): a host batch is uploaded by the call itself and
+        its buffer donated; a caller's device array is never donated; a
+        tracer (the lookup inside a caller's jit) gets the raw composition.
+        Every other kind, and ``plan="host"``, runs eagerly: ``lookup.put``
+        (the batch to the device), the search, ``lookup.gather`` (found and
+        values from the sorted columns)."""
+        fused = self._fused
+        kind = self.config.kind
+        with span("index.lookup", kind=kind):
+            if fused is not None:
+                raw, data, owned, shared = fused
+                if isinstance(queries, jax.core.Tracer):
+                    rank, found, vals = raw(queries, *data)
+                else:
+                    if isinstance(queries, jax.Array):
+                        fn, q = shared, queries
+                    else:
+                        fn, q = owned, np.asarray(queries)
+                    with span("lookup.fused"):
+                        t0 = time.perf_counter()
+                        rank, found, vals = fn(q, *data)
+                        get_registry().histogram(
+                            "dispatch_staging_seconds", path="lookup") \
+                            .observe(time.perf_counter() - t0)
+                path = "fused"
+            else:
+                with span("lookup.put"):
+                    q = jnp.asarray(queries)
+                rank = self.search(q)
+                with span("lookup.gather"):
+                    found, vals = _found_values(q, rank, self.keys_sorted,
+                                                self.values_sorted)
+                path = "eager"
+            get_registry().counter("index_lookup_dispatches", kind=kind,
+                                   path=path).inc()
             return LookupResult(rank=rank, found=found, values=vals)
 
     def delete(self, keys):
@@ -394,6 +445,28 @@ class Index:
     @property
     def tree_bytes(self) -> int:
         return int(getattr(self.impl, "tree_bytes", 0))
+
+
+def _found_values(q, rank, keys_sorted, values_sorted):
+    """Found flags and values of a rank batch from the sorted columns: the
+    clamp and the two gathers that end every frozen lookup, eager or
+    fused."""
+    n = keys_sorted.shape[0]
+    safe = jnp.minimum(rank, n - 1)
+    found = (rank < n) & (jnp.take(keys_sorted, safe, axis=0) == q)
+    vals = None
+    if values_sorted is not None:
+        vals = jnp.take(values_sorted, safe, axis=0)
+    return found, vals
+
+
+def _lookup_program(search):
+    """The traceable frozen lookup over a rank search ``search(q, pages)``:
+    ``(q, pages, keys_sorted, values_sorted) -> (rank, found, values)``."""
+    def fused_lookup(q, pages, keys_sorted, values_sorted):
+        rank = search(q, pages)
+        return (rank,) + _found_values(q, rank, keys_sorted, values_sorted)
+    return fused_lookup
 
 
 _MODULES = {

@@ -91,6 +91,7 @@ class TieredIndex:
     specialize: bool = False     # leaf pages baked into the executable
     search_spec: Any = None      # jitted pipeline closing over the pages
     #                              (None unless built with specialize=True)
+    search_spec_raw: Any = None  # traceable q -> ranks behind search_spec
 
     @property
     def tree_bytes(self) -> int:
@@ -250,7 +251,7 @@ def build(keys, *, leaf_width: int | None = None, tile: int = 128,
                               tile=int(tile), clip=n)
     donate = srt.dtype == np.int32
     pages_dev = _page.page_tiles(pages)
-    search_spec = None
+    search_spec = spec_pipe = None
     if specialize:
         # specialization mode (DESIGN.md §10): the SAME traceable pipeline,
         # re-staged with the device leaf array closed over — the jitted
@@ -273,7 +274,7 @@ def build(keys, *, leaf_width: int | None = None, tile: int = 128,
         search_fused=functools.partial(
             jax.jit, donate_argnums=(0,) if donate else ())(pipeline),
         donate=donate, plan=plan, specialize=specialize,
-        search_spec=search_spec)
+        search_spec=search_spec, search_spec_raw=spec_pipe)
 
 
 @functools.partial(jax.jit, static_argnames=("leaf_width", "n"))

@@ -150,6 +150,21 @@ def test_fused_search_compiles(one_chip, monkeypatch, leaf_width):
 
 
 @pytest.mark.parametrize("leaf_width", LEAF_WIDTHS)
+def test_fused_lookup_compiles(one_chip, monkeypatch, leaf_width):
+    """The frozen ``Index.lookup`` program: the search pipeline, the clamp
+    and the found/value gathers from the sorted columns, all arguments."""
+    from repro.core.api import _lookup_program
+    num_pages, page_of = _fused_top(leaf_width)
+    n = num_pages * leaf_width
+    lookup = _lookup_program(tiered._make_pipeline(
+        page_of, num_pages=num_pages, stride=leaf_width, tile=TILE, clip=n))
+    pages = _spec((num_pages, leaf_width // 128, 128), jnp.int32, one_chip)
+    col = _spec((n,), jnp.int32, one_chip)
+    q = _spec((N_QUERIES,), jnp.int32, one_chip)
+    _assert_fused(monkeypatch, lookup, (q, pages, col, col), n * 4)
+
+
+@pytest.mark.parametrize("leaf_width", LEAF_WIDTHS)
 def test_fused_scan_compiles(one_chip, monkeypatch, leaf_width):
     num_pages, page_of = _fused_top(leaf_width)
     span_of = tiered._make_span_of(page_of, np.int32)
