@@ -1,11 +1,13 @@
 """The load the window offers, through the served path, timed on the host.
 
-Reads go through the program's ``MicroBatchQueue`` over its
-``index_probe_fn``, one ``submit`` of one key per request, then
-``result()`` and ``jax.device_get`` on one client thread, in send order,
-as a client does. Updates call the store's ``insert`` with the request's
-one key on a writer thread, so a write that blocks delays no other
-request's send.
+Each request is sent as its operation says (``bench/deployment.py``).
+One sent through the queue is one ``submit`` to the program's
+``MicroBatchQueue`` over the deployment's probe function (YCSB reads: one
+key), then ``result()`` and ``jax.device_get`` on one client thread, in
+send order, as a client does. One called on a client thread (YCSB
+updates: the store's ``insert`` with the request's one key) runs on a
+thread of its operation's own, in send order, so a call that blocks
+delays no other request's send.
 
 The loop is open: one sender sends each request at its due time and never
 waits for a reply; latency runs from the due time to the answer on the
@@ -26,6 +28,8 @@ import time
 
 import jax
 import numpy as np
+
+from bench.deployment import QUEUE
 
 NULL = contextlib.nullcontext()
 RESULT_WAIT_S = 60.0       # how long past the close an answer is awaited
@@ -99,34 +103,47 @@ class Log:
         self.t_due = np.full(n, np.nan)
         self.t_send = np.full(n, np.nan)
         self.t_done = np.full(n, np.nan)
-        self.found = np.zeros(n, bool)
-        self.value = np.zeros(n, np.int32)
-        self.rank = np.zeros(n, np.int32)
+        self.answer = [None] * n      # what the operation returned
         self.sent = np.zeros(n, bool)
         self.errors = []
 
 
 class Clients:
-    """Client threads: one reader that waits for answers, one writer."""
+    """Client threads: one that waits for the queue's answers, one for
+    each operation called on a client thread that the window sends."""
 
-    def __init__(self, log: Log, keys, wvals, write_fn, spans: Spans):
+    def __init__(self, log: Log, keys, requests, dep, operations: dict,
+                 spans: Spans):
         self.log = log
         self.keys = keys
-        self.wvals = wvals
-        self.write_fn = write_fn
+        self.args = requests.args
+        self.dep = dep
         self.spans = spans
         self.reads = _queue.SimpleQueue()
-        self.writes = _queue.SimpleQueue()
         self._lock = threading.Lock()
         self.inflight = 0             # requests handed over, not finished
-        self.threads = [threading.Thread(target=self._reader, daemon=True)]
-        if write_fn is not None:
-            self.threads.append(threading.Thread(target=self._writer,
-                                                 daemon=True))
+        self.rows = answer = None
+        self.route = []               # per operation: its client queue,
+        calls = []                    # or None: sent through the queue
+        for name in requests.ops:
+            op = operations[name]
+            if op.via == QUEUE:
+                self.rows, answer = op.rows, op.answer
+                self.route.append(None)
+                continue
+            q = _queue.SimpleQueue()
+            self.route.append(q)
+            if requests.of(name).any():
+                calls.append(threading.Thread(
+                    target=self._caller, args=(name, op, q), daemon=True))
+        self.threads = [threading.Thread(target=self._reader,
+                                         args=(answer,), daemon=True)]
+        self.threads += calls
+        self._queues = [self.reads] + [q for q in self.route if q is not None]
         for t in self.threads:
             t.start()
 
-    def _reader(self):
+    def _reader(self, answer):
         log = self.log
         while True:
             item = self.reads.get()
@@ -136,9 +153,7 @@ class Clients:
             try:
                 with self.spans("bench.result"):
                     res = jax.device_get(fut.result(timeout=RESULT_WAIT_S))
-                log.found[i] = bool(res.found[0])
-                log.value[i] = int(res.values[0])
-                log.rank[i] = int(res.rank[0])
+                log.answer[i] = answer(res)
                 log.t_done[i] = time.perf_counter()
             except Exception as e:            # noqa: BLE001 — a failed read
                 log.errors.append(f"read {i}: {e!r}")
@@ -153,19 +168,20 @@ class Clients:
         with self._lock:
             self.inflight -= 1
 
-    def _writer(self):
+    def _caller(self, name: str, op, q):
         log = self.log
+        span = f"bench.{name}"
         while True:
-            i = self.writes.get()
+            i = q.get()
             if i is None:
                 return
             log.t_send[i] = time.perf_counter()
             try:
-                with self.spans("bench.write"):
-                    self.write_fn(self.keys[i:i + 1], self.wvals[i:i + 1])
+                with self.spans(span):
+                    log.answer[i] = op.call(self.dep, self.keys, self.args, i)
                 log.t_done[i] = time.perf_counter()
-            except Exception as e:            # noqa: BLE001 — a failed write
-                log.errors.append(f"write {i}: {e!r}")
+            except Exception as e:            # noqa: BLE001 — a failed call
+                log.errors.append(f"{name} {i}: {e!r}")
             self.done_one()
 
     def close(self, wait_s: float = RESULT_WAIT_S):
@@ -175,25 +191,27 @@ class Clients:
         end = time.perf_counter() + wait_s
         while self.inflight and time.perf_counter() < end:
             time.sleep(0.001)
-        self.reads.put(None)
-        self.writes.put(None)
+        for q in self._queues:
+            q.put(None)
         for t in self.threads:
             t.join(max(0.0, end - time.perf_counter()))
         return not any(t.is_alive() for t in self.threads)
 
 
-def send_read(queue, clients: Clients, log: Log, i: int, spans: Spans):
+def send_queued(queue, clients: Clients, log: Log, i: int, spans: Spans):
     log.t_send[i] = time.perf_counter()
     log.sent[i] = True
     with spans("bench.send"):
-        fut = queue.submit(clients.keys[i:i + 1])
+        fut = queue.submit(clients.rows(clients.keys, clients.args, i))
     clients.hand_over(clients.reads, (i, fut))
 
 
-def open_loop(queue, clients: Clients, is_read, due, t0: float,
+def open_loop(queue, clients: Clients, op, due, t0: float,
               spans: Spans) -> None:
-    """Send request i at ``t0 + due[i]``, never waiting for replies."""
+    """Send request i, of operation ``op[i]``, at ``t0 + due[i]``, never
+    waiting for replies."""
     log = clients.log
+    route = [clients.route[k] for k in op.tolist()]
     n = due.size
     log.t_due[:] = t0 + due
     i = 0
@@ -204,10 +222,11 @@ def open_loop(queue, clients: Clients, is_read, due, t0: float,
             time.sleep(t - now)
             continue
         while i < n and log.t_due[i] <= now:
-            if is_read[i]:
-                send_read(queue, clients, log, i, spans)
+            q = route[i]
+            if q is None:
+                send_queued(queue, clients, log, i, spans)
             else:
                 log.sent[i] = True
-                clients.hand_over(clients.writes, i)
+                clients.hand_over(q, i)
             i += 1
 

@@ -12,6 +12,10 @@ write's value or a newer one. A read that overlaps writes to its key may
 return the value before them or any of theirs that had started before
 the read's answer came back. Every read due in the window is compared.
 
+This is the default reference of a deployment kind
+(``bench/deployment.py``): ``Reference.check`` answers the point reads
+and updates of ``POINT_OPERATIONS``.
+
 The controls put the reference in the program's place, one guarantee
 broken: ``f32`` searches keys cast to float32 (the nearest precision below
 the int32 keys the configuration states; keys above 2^24 collide), and
@@ -60,6 +64,38 @@ class Reference:
         if kind == "stale":
             return self.lookup(q)
         raise ValueError(f"unknown control {kind!r}; want one of {CONTROLS}")
+
+    def check(self, outcome) -> dict:
+        """Each number compared, with its limit, for a window's point
+        reads and writes (``deployment.Outcome``)."""
+        return compare(self, point_reads(outcome), outcome.writes,
+                       outcome.readback, outcome.compare_rank)
+
+    def check_control(self, kind: str, outcome) -> dict:
+        """The numbers ``check`` compares, for the control's answers to
+        the same requests."""
+        creads, cback = control_answers(self, kind, point_reads(outcome),
+                                        outcome.readback)
+        return compare(self, creads, outcome.writes, cback,
+                       outcome.compare_rank)
+
+
+def point_reads(outcome, op: str = "read") -> dict:
+    """The sent requests of a point-read operation, with the answers kept
+    (found, value, rank) and their times."""
+    i = outcome.sent(op)
+    answered = outcome.answered(i)
+    got = [outcome.log.answer[j] if ok else (False, 0, 0)
+           for j, ok in zip(i.tolist(), answered.tolist())]
+    found, value, rank = (np.asarray(c) for c in zip(*got)) if got else \
+        ((),) * 3
+    t_done = outcome.log.t_done[i]
+    return {"key": outcome.keys[i], "found": np.asarray(found, bool),
+            "value": np.asarray(value, np.int32),
+            "rank": np.asarray(rank, np.int32),
+            "t_send": outcome.log.t_send[i],
+            "t_done": np.where(answered, t_done, np.inf),
+            "answered": answered}
 
 
 def _write_log(writes: dict) -> dict:
@@ -140,7 +176,3 @@ def compare(ref: Reference, reads: dict, writes: dict, readback: dict,
         checks["failed_writes"] = int((~np.isfinite(writes["t_ack"])).sum())
         checks["lost_writes"] = lost_writes(writes, readback)
     return {k: {"value": v, "limit": 0} for k, v in checks.items()}
-
-
-def is_correct(checks: dict) -> bool:
-    return all(c["value"] <= c["limit"] for c in checks.values())

@@ -2,24 +2,37 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-A cell of ``BENCHMARK.json`` names a configuration (``bench/configs/``,
-built by ``bench/deploy/<kind>.py``) and a traffic mix
-(``bench/traffic/<name>.json``); a per-layer metric is read by
-``bench/metrics/<name>.py``. All are found by name, so a cell, a
+A cell of ``BENCHMARK.json`` names a configuration (``bench/configs/``)
+and a traffic mix (``bench/traffic/<name>.json``); a per-layer metric is
+read by ``bench/metrics/<name>.py``. All are found by name, so a cell, a
 configuration, a mix or a metric is added as files and an entry, not as an
 edit.
 
-One run is one process: it makes the YCSB table from ``--seed``, builds
-the deployment through ``build_index``, warms every shape the window will
-use, offers the traffic for ``--seconds`` through the served path, then
-compares every answer with the plain NumPy reference. It needs a TPU: with
+The configuration's ``kind`` names its deployment kind,
+``bench/deploy/<kind>.py``, which owns everything about the deployment
+(the contract is in ``bench/deployment.py``): ``build`` (required), and,
+each with a default, ``make_table`` (the data from ``--seed``; default the
+YCSB usertable), ``OPERATIONS`` (what a mix may ask, how each operation
+is sent and how its arguments are drawn; default YCSB point reads through
+the queue and updates on a writer thread), ``make_reference`` (the plain
+reference and the checks that decide ``correct``; default
+``reference.Reference``) and ``LEAST_BYTES`` (each operation's least
+bytes a dispatch, for the roofline readers; default ``roofline``'s point
+lookups). So a new deployment is new files as well. A reader file may
+carry its own test case (``bench/record.py``).
+
+One run is one process: it makes the table from ``--seed``, builds the
+deployment, warms every shape the window will use, offers the traffic
+for ``--seconds`` through the served path, then compares every answer
+with the plain reference. Read latency (``read_*``) is over every request
+of a read operation. It needs a TPU: with
 none, or with the kernels in the Pallas interpreter, it exits 1 and prints
 no result. Earlier lines report set-up, the generator's lateness, compiles
 in the window and ``peak_bytes_in_use``; the numbers compared and their
 limits are the last lines on standard error; the last line on standard
 output is the JSON result. ``--trace 1`` traces part of the window with the
 JAX profiler and reports the per-layer metrics instead of the end-to-end
-ones.
+ones; the readers of the program's registry leave the traced part out.
 
 Calibration options, not used by the benchmark's runs: ``--sweep r1,r2``
 offers each open-loop rate in turn after one set-up and prints what the
@@ -48,10 +61,12 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(ROOT))
 
-from bench import (drive, peaks, reference, roofline,  # noqa: E402
-                   workload, ycsb)
+from bench import (deployment, drive, peaks, workload,  # noqa: E402
+                   ycsb)
 from bench.record import RunRecord  # noqa: E402
 
+NO_READBACK = {"key": np.zeros(0, np.int32), "found": np.zeros(0, bool),
+               "value": np.zeros(0, np.int32)}
 TRACE_SHARE = (0.3, 0.2)     # traced part of the window: start, length
 TRACE_MAX_S = 4.0
 
@@ -76,7 +91,7 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
 
     def mine(m):
         return "workloads" not in m or name in m["workloads"]
-    return {"cell": cell, "config": config, "traffic": traffic,
+    return {"root": root, "cell": cell, "config": config, "traffic": traffic,
             "end_to_end": [m for m in spec["end_to_end"] if mine(m)],
             "per_layer": [m for m in spec["per_layer"] if mine(m)]}
 
@@ -193,12 +208,13 @@ class Cell:
         self.config = spec["config"]
         self.traffic = spec["traffic"]
         self.spans = drive.Spans()
+        self.kind = deployment.kind_of(
+            deploy_kind(self.config["kind"], spec["root"]))
         t = time.perf_counter()
-        self.table = ycsb.Table(self.config["recordcount"], seed)
+        self.table = self.kind.make_table(self.config, seed)
         self.data_s = time.perf_counter() - t
         t = time.perf_counter()
-        self.dep = deploy_kind(self.config["kind"]).build(
-            self.config, self.table, workdir)
+        self.dep = self.kind.build(self.config, self.table, workdir)
         self.build_s = time.perf_counter() - t
         self.probe = drive.Probe(self.dep.probe_fn, self.spans)
         self.writes = {"key": [], "value": [], "t_start": [], "t_ack": []}
@@ -234,8 +250,8 @@ class Cell:
         keys = self.table.keys[reqs.record]
         lg = drive.Log(reqs.n)
         queue = drive.make_queue(self.probe, self.dep.index_config)
-        clients = drive.Clients(lg, keys, reqs.wval, self.dep.write,
-                                self.spans)
+        clients = drive.Clients(lg, keys, reqs, self.dep,
+                                self.kind.operations, self.spans)
         reg = obs.get_registry()
         before = reg.snapshot()
         tracer = _Tracer(self, seconds, trace_dir) if trace_dir else None
@@ -246,8 +262,7 @@ class Cell:
         self.probe.record = True
         if tracer:
             tracer.start(t0)
-        drive.open_loop(queue, clients, reqs.is_read, reqs.due, t0,
-                        self.spans)
+        drive.open_loop(queue, clients, reqs.op, reqs.due, t0, self.spans)
         if tracer:
             tracer.join()
         drained = clients.close()
@@ -263,23 +278,23 @@ class Cell:
         compiles = COMPILES.n - compiles0
         is_read = reqs.is_read
         sent = lg.sent
-        # the writer applied writes in send order: that is the apply order
+        # a writer applies its writes in send order: that is the apply order
         w = np.nonzero(~is_read & sent)[0]
         w = w[np.argsort(lg.t_send[w], kind="stable")]
-        for i in w:
-            self.writes["key"].append(int(keys[i]))
-            self.writes["value"].append(int(reqs.wval[i]))
-            self.writes["t_start"].append(float(lg.t_send[i]))
-            self.writes["t_ack"].append(float(lg.t_done[i]))
-        r = np.nonzero(is_read & sent)[0]
-        reads = {"key": keys[r], "found": lg.found[r], "value": lg.value[r],
-                 "rank": lg.rank[r], "t_send": lg.t_send[r],
-                 "t_done": np.where(np.isfinite(lg.t_done[r]), lg.t_done[r],
-                                    np.inf),
-                 "answered": np.isfinite(lg.t_done[r])}
+        wkey = np.zeros(w.size, np.int64)
+        wval = np.zeros(w.size, np.int64)
+        for k, name in enumerate(reqs.ops):
+            m = reqs.op[w] == k
+            if m.any():
+                wkey[m], wval[m] = self.kind.operations[name].written(
+                    keys, reqs.args, w[m])
+        self.writes["key"] += wkey.tolist()
+        self.writes["value"] += wval.tolist()
+        self.writes["t_start"] += lg.t_send[w].tolist()
+        self.writes["t_ack"] += lg.t_done[w].tolist()
         return {"t0": t0, "t_end": t_end, "log": lg,
-                "t_gave_up": t_gave_up,
-                "reads": reads, "is_read": is_read, "sent": sent,
+                "t_gave_up": t_gave_up, "requests": reqs, "keys": keys,
+                "is_read": is_read, "sent": sent,
                 "drained": drained, "compiles": compiles, "queue": q,
                 "gc": pauses,
                 "registry": (before, after), "tracer": tracer,
@@ -324,6 +339,8 @@ class _Tracer(threading.Thread):
         self.t0 = 0.0
         self.span = (0.0, 0.0)
         self.path = None
+        self.registry = ()       # snapshots as tracing starts and ends
+        self.registry_s = 0.0
 
     def start(self, t0: float):
         self.t0 = t0
@@ -335,6 +352,9 @@ class _Tracer(threading.Thread):
         time.sleep(max(0.0, self.t0 + self.delay - time.perf_counter()))
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
+        reg = obs.get_registry()
+        first = reg.snapshot()
+        t = time.perf_counter()
         obs.TRACER.enable()
         self.cell.spans.on = True
         jax.profiler.start_trace(self.out_dir, profiler_options=opts)
@@ -345,6 +365,8 @@ class _Tracer(threading.Thread):
         jax.profiler.stop_trace()
         self.cell.spans.on = False
         obs.TRACER.disable()
+        self.registry_s = time.perf_counter() - t
+        self.registry = (first, reg.snapshot())
         self.span = (a, b)
         found = glob.glob(f"{self.out_dir}/**/*.xplane.pb", recursive=True)
         self.path = found[0] if found else None
@@ -380,6 +402,11 @@ def end_to_end(cell: Cell, win: dict, seconds: float) -> dict:
     return out
 
 
+def is_correct(checks: dict) -> bool:
+    """Every number the kind's reference compared is within its limit."""
+    return all(c["value"] <= c["limit"] for c in checks.values())
+
+
 def failed(win: dict) -> int:
     """Requests sent in the window that never finished: reads with no
     answer, writes with no acknowledgement."""
@@ -404,24 +431,37 @@ def lateness(win: dict) -> str:
 
 
 # ------------------------------------------------------------ per layer
-def traced_bytes(cell: Cell, win: dict, ref) -> int:
-    """Least bytes of the batches dispatched inside the traced part."""
+def traced_bytes(cell: Cell, win: dict, ref) -> dict:
+    """Least bytes of the dispatches inside the traced part, by
+    operation: the queue's batches, and each call of a client operation
+    (``bench/deployment.py`` LEAST_BYTES)."""
     a, b = win["tracer"].span
-    return sum(roofline.batch_bytes(ref.keys, np.asarray(q))
-               for t, q in win["batches"] if a <= t <= b)
+    reqs, keys, lg = win["requests"], win["keys"], win["log"]
+    out = {}
+    for name, least in cell.kind.least_bytes.items():
+        if cell.kind.operations[name].via == deployment.QUEUE:
+            batches = [q for t, q in win["batches"] if a <= t <= b]
+        else:
+            idx = np.nonzero(reqs.of(name) & win["sent"] & (lg.t_send >= a)
+                             & (lg.t_send <= b))[0]
+            batches = [dict({k: v[i:i + 1] for k, v in reqs.args.items()},
+                            key=keys[i:i + 1]) for i in idx]
+        out[name] = sum(least(ref, x) for x in batches)
+    return out
 
 
 def per_layer(spec: dict, cell: Cell, win: dict, ref, chip) -> tuple:
     from bench import tracing
     tr = win["tracer"]
-    red = tracing.reduce(tr.path) if tr and tr.path else None
+    red = tracing.reduce(tr.path) if tr.path else None
     rec = RunRecord(window_s=win["t_end"] - win["t0"],
                     registry=win["registry"], queue=win["queue"],
                     reduction=red, peaks=chip,
-                    traced_bytes=traced_bytes(cell, win, ref) if red else 0)
+                    traced_bytes=traced_bytes(cell, win, ref),
+                    traced_registry=tr.registry, traced_s=tr.registry_s)
     out = {}
     for m in spec["per_layer"]:
-        v = metric_reader(m["name"]).read(rec)
+        v = metric_reader(m["name"], spec["root"]).read(rec)
         if v is not None:
             out[m["name"]] = {"value": v, "unit": m["unit"]}
     return out, red
@@ -436,8 +476,9 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--sweep", default="",
                     help="calibration: comma-separated open-loop rates")
-    ap.add_argument("--control", choices=reference.CONTROLS,
-                    help="calibration: also compare this control")
+    ap.add_argument("--control",
+                    help="calibration: also compare this control of the "
+                    "kind's reference (default reference: f32, stale)")
     args = ap.parse_args(argv)
 
     spec = load_cell(args.workload)
@@ -466,7 +507,7 @@ def _run(args, spec, dev, count, chip, workdir) -> int:
     if args.sweep:
         return sweep(cell, spec, args)
     reqs = workload.generate(spec["traffic"], cell.table.n, args.seed,
-                             args.seconds)
+                             args.seconds, cell.kind.operations)
     cell.setup_s = time.perf_counter() - T_PROCESS
     trace_dir = os.path.join(workdir, "trace") if args.trace else None
     win = cell.window(reqs, args.seconds, trace_dir)
@@ -481,34 +522,34 @@ def _run(args, spec, dev, count, chip, workdir) -> int:
         f"gc: {win['gc']}")
     for e in win["log"].errors[:5]:
         log(f"error: {e}")
-    readback = cell.read_back() if cell.writes["key"] else \
-        {"key": np.zeros(0, np.int32), "found": np.zeros(0, bool),
-         "value": np.zeros(0, np.int32)}
+    readback = cell.read_back() if cell.writes["key"] else NO_READBACK
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
     log(f"peak_bytes_in_use={peak}")
     e2e = end_to_end(cell, win, args.seconds)
-    writes = cell.writes_arrays()
+    outcome = deployment.Outcome(
+        requests=win["requests"], keys=win["keys"], log=win["log"],
+        writes=cell.writes_arrays(), readback=readback,
+        compare_rank=cell.dep.compare_rank)
     table = cell.table
-    compare_rank = cell.dep.compare_rank
     cell.dep.close()
     cell.dep = cell.probe = None
     gc.collect()
     t = time.perf_counter()
-    ref = reference.Reference(table.keys, table.values)
-    checks = reference.compare(ref, win["reads"], writes, readback,
-                               compare_rank)
+    ref = cell.kind.make_reference(cell.config, table)
+    checks = ref.check(outcome)
     log(f"reference: seconds={time.perf_counter() - t:.3f} "
-        f"reads={win['reads']['key'].size} writes={writes['key'].size} "
+        f"reads={int((win['sent'] & win['is_read']).sum())} "
+        f"writes={outcome.writes['key'].size} "
         f"read_back={readback['key'].size}")
     if args.control:
-        creads, cback = reference.control_answers(ref, args.control,
-                                                  win["reads"], readback)
-        cchecks = reference.compare(ref, creads, writes, cback,
-                                    compare_rank)
+        if not hasattr(ref, "check_control"):
+            raise SystemExit(f"the reference of kind {cell.config['kind']!r} "
+                             "has no controls")
+        cchecks = ref.check_control(args.control, outcome)
         log("control " + args.control + ": " + " ".join(
             f"{k}={v['value']}" for k, v in cchecks.items()) +
-            f" correct={reference.is_correct(cchecks)}")
-    correct = reference.is_correct(checks)
+            f" correct={is_correct(cchecks)}")
+    correct = is_correct(checks)
     result = {"correct": correct,
               "attempted": int(win["sent"].sum()),
               "failed": failed(win),
@@ -541,22 +582,21 @@ def _run(args, spec, dev, count, chip, workdir) -> int:
 
 def sweep(cell: Cell, spec: dict, args) -> int:
     """Calibration: each rate in turn, one window each, same set-up."""
-    ref = reference.Reference(cell.table.keys, cell.table.values)
+    ref = cell.kind.make_reference(cell.config, cell.table)
     traffic = dict(spec["traffic"])
     for i, rate in enumerate(float(r) for r in args.sweep.split(",")):
         traffic["arrival"] = {"process": "poisson", "rate_per_s": rate}
         reqs = workload.generate(traffic, cell.table.n, args.seed + i,
-                                 args.seconds)
+                                 args.seconds, cell.kind.operations)
         cell.setup_s = 0.0
         win = cell.window(reqs, args.seconds)
         e2e = end_to_end(cell, win, args.seconds)
         lg = win["log"]
         ok = np.isfinite(lg.t_done) & win["sent"]
-        checks = reference.compare(ref, win["reads"], cell.writes_arrays(),
-                                   {"key": np.zeros(0, np.int32),
-                                    "found": np.zeros(0, bool),
-                                    "value": np.zeros(0, np.int32)},
-                                   cell.dep.compare_rank)
+        checks = ref.check(deployment.Outcome(
+            requests=reqs, keys=win["keys"], log=lg,
+            writes=cell.writes_arrays(), readback=NO_READBACK,
+            compare_rank=cell.dep.compare_rank))
         checks.pop("lost_writes", None)
         last = np.nanmax(lg.t_done[ok]) - win["t0"] if ok.any() else 0.0
         log(f"sweep rate={rate} offered={reqs.n / args.seconds:.1f} "
@@ -567,7 +607,7 @@ def sweep(cell: Cell, spec: dict, args) -> int:
             + f" flushes={win['queue']['flushes']} "
             f"queries={win['queue']['queries']} "
             f"compiles_in_window={win['compiles']} "
-            f"correct={reference.is_correct(checks)} | {lateness(win)}")
+            f"correct={is_correct(checks)} | {lateness(win)}")
     return 0
 
 
